@@ -12,8 +12,8 @@
 #   make bench-all   every benchmark suite (paper tables + streaming)
 #   make lint        byte-compile + import smoke over all python trees
 #
-# The container is CPU-only; Pallas kernels run with interpret=True there and
-# compile to Mosaic on TPU — same commands either way.
+# Tests run on the CPU (JAX_PLATFORMS=cpu; Pallas kernels in interpret mode).
+# The chip path is `python chip_smoke.py` (`--chips 4` for the stage-2 farm).
 
 PY       ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
@@ -32,20 +32,29 @@ test-mesh:
 	$(PY) -m pytest -x -q tests/test_stage2_mesh.py tests/test_block_cache.py \
 	tests/test_resilience.py tests/test_shards.py
 
+# The stage-2 farm suite runs each device count in one process over
+# jax.local_devices()[:D]; on the CPU those devices are virtual and must be
+# in XLA_FLAGS before jax starts, hence its own process.  Single-threaded
+# eigen pins one compute thread per virtual device, so device parallelism
+# comes only from the farm itself.
+MESH_XLA_FLAGS := --xla_cpu_multi_thread_eigen=false \
+	--xla_force_host_platform_device_count=4
+
 bench:
-	$(PY) -m benchmarks.run streaming stage2 stage2_mesh polish table3 disk
+	$(PY) -m benchmarks.run streaming stage2 polish table3 disk
+	XLA_FLAGS="$(MESH_XLA_FLAGS)" $(PY) -m benchmarks.run stage2_mesh
 
 # smoke-sized records must not clobber the committed BENCH_*.json trajectory
 bench-smoke:
 	BENCH_SMOKE=1 \
 	BENCH_STREAMING_JSON=/tmp/BENCH_streaming.smoke.json \
 	BENCH_STAGE2_STREAM_JSON=/tmp/BENCH_stage2_stream.smoke.json \
-	BENCH_STAGE2_MESH_JSON=/tmp/BENCH_stage2_mesh.smoke.json \
 	BENCH_POLISH_JSON=/tmp/BENCH_polish.smoke.json \
 	BENCH_CV_GRID_JSON=/tmp/BENCH_cv_grid.smoke.json \
 	BENCH_DISK_STREAM_JSON=/tmp/BENCH_disk_stream.smoke.json \
-	$(PY) -m benchmarks.run streaming stage2 stage2_mesh polish table3 \
-	disk trace_smoke
+	$(PY) -m benchmarks.run streaming stage2 polish table3 disk trace_smoke
+	BENCH_SMOKE=1 BENCH_STAGE2_MESH_JSON=/tmp/BENCH_stage2_mesh.smoke.json \
+	XLA_FLAGS="$(MESH_XLA_FLAGS)" $(PY) -m benchmarks.run stage2_mesh
 
 # streamed fit under a Tracer: asserts >=1 span per core pipeline category
 # in the exported Chrome-trace JSON, zero events on the disabled path, and

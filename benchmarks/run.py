@@ -1,8 +1,9 @@
 """Benchmark harness: one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows.  Roofline numbers come from the
-dry-run artifacts (results/dryrun) via ``repro.analysis.roofline``, not from
-wall-clock — this container is CPU-only and TPU v5e is the target.
+Prints ``name,us_per_call,derived`` CSV rows.  Each suite records the JAX
+backend it ran on; a timing taken on the CPU backend is a CPU timing, not a
+TPU one.  Roofline numbers come from the dry-run artifacts (results/dryrun)
+via ``repro.analysis.roofline``.
 
     PYTHONPATH=src python -m benchmarks.run [table2 table3 shrinking fig3
                                              eigdrop kernels]
@@ -15,6 +16,8 @@ def main() -> None:
                             polish, shrinking, stage2_mesh, stage2_stream,
                             streaming, table2_solvers, table3_cv_grid,
                             trace_smoke)
+    from repro.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     suites = {
         "table2": table2_solvers.run,
         "table3": table3_cv_grid.run,

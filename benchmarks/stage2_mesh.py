@@ -9,19 +9,19 @@ For each device count D the same (G, TaskBatch) pair is solved by
 recording wall-clock and the mesh-level H2D bytes of the first full pass —
 the number that must NOT scale with D for the overlapped farm (the paper's
 "parallelism + more RAM" leg: many cores feeding multiple devices out of one
-large-RAM host copy of G).  Device counts beyond the container's real
-hardware come from `--xla_force_host_platform_device_count`, which must be
-set before jax imports, so each D runs in a fresh subprocess (worker mode).
+large-RAM host copy of G).  Every D runs in this one process, over
+``jax.local_devices()[:D]``: a child process could not reach an accelerator
+the parent already holds.  On the CPU the devices are virtual and come from
+the environment (`make bench` sets ``XLA_FLAGS``):
 
-    PYTHONPATH=src python -m benchmarks.run stage2_mesh
-    BENCH_SMOKE=1 PYTHONPATH=src python -m benchmarks.run stage2_mesh  # fast
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src python -m benchmarks.run stage2_mesh
+    BENCH_SMOKE=1 ...                                     # fast
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 OUT_PATH = os.environ.get("BENCH_STAGE2_MESH_JSON", "BENCH_stage2_mesh.json")
@@ -40,31 +40,15 @@ PROBLEM = (2_400, 128, 4, 12) if SMOKE else (8_000, 192, 4, 25)
 TILE = 1_024 if SMOKE else 2_048
 
 
-def _worker(n_dev: int) -> None:
-    """Runs inside the XLA_FLAGS=...device_count=D subprocess: solve the same
-    problem through both farms and print one JSON record per mode."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
+def _records(G, tasks, devices) -> list:
+    """Solve the same problem through both farms on ``devices``; one record
+    per mode."""
     from benchmarks.common import timeit
-    from repro.core import (KernelParams, SolverConfig, StreamConfig,
-                            compute_factor, solve_tasks_streamed)
-    from repro.core.ovo import build_ovo_tasks
-    from repro.data import make_multiclass
+    from repro.core import SolverConfig, StreamConfig, solve_tasks_streamed
 
-    n, budget, classes, max_epochs = PROBLEM
-    x, y = make_multiclass(n, p=8, n_classes=classes, seed=7)
-    _, labels = np.unique(y, return_inverse=True)
-    fac = compute_factor(jnp.asarray(x, jnp.float32),
-                         KernelParams("rbf", gamma=0.2), budget)
-    G = np.asarray(fac.G)
-    tasks, _ = build_ovo_tasks(labels, classes, 4.0)
+    n, _, _, max_epochs = PROBLEM
     config = SolverConfig(tol=1e-2, max_epochs=max_epochs)
     scfg = StreamConfig(tile_rows=TILE)
-    devices = jax.local_devices()
-    assert len(devices) == n_dev, (len(devices), n_dev)
-
     records = []
     for mode, overlap in (("serial", False), ("overlapped", True)):
         holder = {}
@@ -79,7 +63,8 @@ def _worker(n_dev: int) -> None:
         t = timeit(solve, repeats=1 if SMOKE else 5)
         st = holder["st"]
         records.append({
-            "mode": mode, "n_devices": n_dev, "n": n, "rank": G.shape[1],
+            "mode": mode, "n_devices": len(devices), "n": n,
+            "rank": G.shape[1],
             "n_tasks": tasks.n_tasks, "tile_rows": st.tile_rows,
             "seconds": t, "bytes_h2d": st.bytes_h2d,
             "bytes_put": st.bytes_put,
@@ -87,35 +72,30 @@ def _worker(n_dev: int) -> None:
             "epochs": st.epochs, "full_passes": st.full_passes,
             "prefetch_final": st.prefetch_final,
         })
-    print("BENCH_JSON:" + json.dumps(records), flush=True)
+    return records
 
 
 def run() -> None:
-    from benchmarks.common import emit, provenance
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
 
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
+    from benchmarks.common import emit, provenance
+    from repro.core import KernelParams, compute_factor
+    from repro.core.ovo import build_ovo_tasks
+    from repro.data import make_multiclass
+
+    n, budget, classes, _ = PROBLEM
+    x, y = make_multiclass(n, p=8, n_classes=classes, seed=7)
+    _, labels = np.unique(y, return_inverse=True)
+    fac = compute_factor(jnp.asarray(x, jnp.float32),
+                         KernelParams("rbf", gamma=0.2), budget)
+    G = np.asarray(fac.G)
+    tasks, _ = build_ovo_tasks(labels, classes, 4.0)
+    devices = jax.local_devices()
     records = []
-    for n_dev in DEVICE_COUNTS:
-        env = dict(os.environ)
-        # Single-threaded eigen pins ONE compute thread per virtual device:
-        # device parallelism then comes only from the farm itself, not from
-        # the intra-op pool racing the scheduler (which swamps the
-        # measurement with 2x run-to-run noise on small containers).
-        env["XLA_FLAGS"] = ("--xla_cpu_multi_thread_eigen=false "
-                            f"--xla_force_host_platform_device_count={n_dev}")
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.stage2_mesh", "--worker",
-             str(n_dev)],
-            capture_output=True, text=True, timeout=3600, env=env,
-            cwd=os.path.join(os.path.dirname(__file__), ".."))
-        if out.returncode != 0:
-            raise RuntimeError(f"stage2_mesh worker D={n_dev} failed:\n"
-                               f"{out.stderr[-3000:]}")
-        payload = [ln for ln in out.stdout.splitlines()
-                   if ln.startswith("BENCH_JSON:")][-1]
-        recs = json.loads(payload[len("BENCH_JSON:"):])
+    for n_dev in (d for d in DEVICE_COUNTS if d <= len(devices)):
+        recs = _records(G, tasks, devices[:n_dev])
         records.extend(recs)
         by_mode = {r["mode"]: r for r in recs}
         speedup = by_mode["serial"]["seconds"] / by_mode["overlapped"]["seconds"]
@@ -136,7 +116,7 @@ def run() -> None:
                      f"per-pass bytes")
 
     payload = {"benchmark": "stage2_mesh",
-               "backend": "cpu",        # workers force host devices
+               "backend": jax.default_backend(),
                "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
                "provenance": provenance(),
                "problem": {"n": PROBLEM[0], "budget": PROBLEM[1],
@@ -149,8 +129,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 2 and sys.argv[1] == "--worker":
-        _worker(int(sys.argv[2]))
-    else:
-        print("name,us_per_call,derived")
-        run()
+    print("name,us_per_call,derived")
+    run()

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dual_solver import SolverConfig
+from repro.core.kernel_fn import HIGHEST
 
 
 @dataclasses.dataclass
@@ -73,7 +74,7 @@ def solve_compact(
     q = jnp.sum(G_rows * G_rows, axis=1)
     alpha = (jnp.zeros((n,), jnp.float32) if alpha0 is None
              else jnp.clip(jnp.asarray(alpha0, jnp.float32), 0.0, c))
-    w = (alpha * y) @ G_rows
+    w = jnp.dot(alpha * y, G_rows, precision=HIGHEST)
     unchanged = jnp.zeros((n,), jnp.int32)
 
     period = config.full_pass_period if config.shrink else 1

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core.dual_solver import SolveResult, SolverConfig, TaskBatch, solve_batch
 from repro.core.faults import classify_error
 from repro.core.kernel_fn import KernelParams, apply_epilogue
@@ -84,7 +82,7 @@ def solve_tasks_sharded(
     tspec = P(task_axes)
 
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, None), tspec, tspec, tspec, tspec),
         out_specs=SolveResult(tspec, tspec, P(task_axes), P(task_axes),
                               P(task_axes), P(task_axes)),

@@ -32,6 +32,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.kernel_fn import HIGHEST
+
 DELTA_EPS = 0.0   # "did not change": exact in float (bound hits are exact clips)
 Q_FLOOR = 1e-12   # guards division for zero rows (padding)
 
@@ -89,7 +91,7 @@ def epoch_ref(G, idx, y, c, q, alpha, w, unchanged, shrink_k, full_pass):
         a_i, c_i, y_i, q_i = alpha[i], c[i], y[i], q[i]
         active = jnp.logical_and(
             c_i > 0.0, jnp.logical_or(full_pass, unchanged[i] < shrink_k))
-        g = 1.0 - y_i * jnp.dot(w, row)
+        g = 1.0 - y_i * jnp.dot(w, row, precision=HIGHEST)
         pg = _projected_gradient(g, a_i, c_i)
         a_new = jnp.clip(a_i + g / jnp.maximum(q_i, Q_FLOOR), 0.0, c_i)
         a_new = jnp.where(active, a_new, a_i)
@@ -111,7 +113,7 @@ def epoch_ref(G, idx, y, c, q, alpha, w, unchanged, shrink_k, full_pass):
 
 def _init_w(G, idx, y, alpha0):
     rows = G[idx]                                   # (n_pad, B)
-    return (alpha0 * y) @ rows
+    return jnp.dot(alpha0 * y, rows, precision=HIGHEST)
 
 
 @partial(jax.jit, static_argnames=("config",))
@@ -140,7 +142,7 @@ def solve_one(G, idx, y, c, alpha0, config: SolverConfig) -> SolveResult:
     init = ((alpha0, w0, unchanged0), jnp.float32(jnp.inf), jnp.int32(0),
             jnp.bool_(False))
     (alpha, w, _), viol, epochs, _ = jax.lax.while_loop(cond, body, init)
-    dual = jnp.sum(alpha) - 0.5 * jnp.dot(w, w)
+    dual = jnp.sum(alpha) - 0.5 * jnp.dot(w, w, precision=HIGHEST)
     n_sv = jnp.sum(alpha > 0.0)
     return SolveResult(alpha, w, epochs, viol, dual, n_sv)
 
@@ -158,7 +160,7 @@ def solve_batch(G, tasks: TaskBatch, config: SolverConfig) -> SolveResult:
 
 def dual_objective(G, idx, y, alpha):
     w = _init_w(G, idx, y, alpha)
-    return jnp.sum(alpha) - 0.5 * jnp.dot(w, w)
+    return jnp.sum(alpha) - 0.5 * jnp.dot(w, w, precision=HIGHEST)
 
 
 def primal_objective(G, idx, y, c, w):
@@ -171,14 +173,14 @@ def primal_objective(G, idx, y, c, w):
     n = jnp.sum(real)
     C = jnp.max(c)
     lam = 1.0 / (C * n)
-    margins = y * (G[idx] @ w)
+    margins = y * jnp.dot(G[idx], w, precision=HIGHEST)
     hinge = jnp.where(real, jnp.maximum(0.0, 1.0 - margins), 0.0)
     # rescale to the dual's units: dual D corresponds to primal C * sum hinge
-    return 0.5 * jnp.dot(w, w) + C * jnp.sum(hinge), lam, n
+    return 0.5 * jnp.dot(w, w, precision=HIGHEST) + C * jnp.sum(hinge), lam, n
 
 
 def duality_gap(G, idx, y, c, alpha):
     w = _init_w(G, idx, y, alpha)
     p, _, _ = primal_objective(G, idx, y, c, w)
-    d = jnp.sum(alpha) - 0.5 * jnp.dot(w, w)
+    d = jnp.sum(alpha) - 0.5 * jnp.dot(w, w, precision=HIGHEST)
     return p - d

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.kernel_fn import KernelParams, gram
+from repro.core.kernel_fn import HIGHEST, KernelParams, gram
 
 # float32 machine epsilon is ~1.19e-7; the paper drops eigenvalues "as soon as
 # the eigenvalues fall below a threshold close to the machine precision times
@@ -61,7 +61,7 @@ class LowRankFactor:
     def features(self, x: jnp.ndarray) -> jnp.ndarray:
         """Map new points into the low-rank feature space (prediction path)."""
         k_xm = gram(x, self.landmarks, self.kernel)
-        return k_xm @ self.projector
+        return jnp.dot(k_xm, self.projector, precision=HIGHEST)
 
 
 def wait_for_factor(G) -> None:
@@ -156,7 +156,8 @@ def compute_factor(
     blocks = []
     for start in range(0, n, block_rows):
         xb = x[start:start + block_rows]
-        blocks.append(gram_fn(xb, landmarks, params) @ projector)
+        blocks.append(jnp.dot(gram_fn(xb, landmarks, params), projector,
+                              precision=HIGHEST))
     G = jnp.concatenate(blocks, axis=0) if len(blocks) > 1 else blocks[0]
 
     return LowRankFactor(

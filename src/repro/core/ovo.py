@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dual_solver import TaskBatch
+from repro.core.kernel_fn import HIGHEST
 
 
 def class_pairs(n_classes: int) -> List[Tuple[int, int]]:
@@ -86,7 +87,7 @@ def build_ovo_tasks(
 
 def ovo_decision_values(features: jnp.ndarray, W: jnp.ndarray) -> jnp.ndarray:
     """(m, B) features x (T, B) per-pair weights -> (m, T) decision values."""
-    return features @ W.T
+    return jnp.dot(features, W.T, precision=HIGHEST)
 
 
 def ovo_vote(decisions: np.ndarray, pairs: List[Tuple[int, int]],

@@ -91,6 +91,7 @@ from repro.core.dual_solver import (DELTA_EPS, Q_FLOOR, SolveResult,
                                     SolverConfig, TaskBatch)
 from repro.core.faults import check as _fault_check
 from repro.core.faults import classify_error
+from repro.core.kernel_fn import HIGHEST
 from repro.core.quant import (GROUP_ROWS, QuantBlock, dequant_rows,
                               encode_rows, group_scales, quantize_block)
 from repro.core.streaming import BYTES_F32, StreamConfig, tune_prefetch
@@ -187,7 +188,7 @@ def smo_epoch_oracle(G, y, c, q, alpha, unchanged, w, *, full_pass: bool,
         a_i, c_i, y_i, q_i = alpha[i], c[i], y[i], q[i]
         active = jnp.logical_and(
             c_i > 0.0, jnp.logical_or(full_pass, unchanged[i] < shrink_k))
-        g = 1.0 - y_i * jnp.dot(w, row)
+        g = 1.0 - y_i * jnp.dot(w, row, precision=HIGHEST)
         at_lo = a_i <= 0.0
         at_hi = a_i >= c_i
         pg = jnp.where(at_lo, jnp.maximum(g, 0.0),
@@ -233,7 +234,7 @@ def _row_sq(G):
 @jax.jit
 def _accum_w(w, G, alpha, y):
     """Warm-start w accumulation: w += (alpha * y) @ G_block."""
-    return w + (alpha * y) @ G
+    return w + jnp.dot(alpha * y, G, precision=HIGHEST)
 
 
 @jax.jit

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.faults import check as _fault_check
-from repro.core.kernel_fn import KernelParams, gram
+from repro.core.kernel_fn import HIGHEST, KernelParams, gram
 from repro.core.quant import (GROUP_ROWS, QuantBlock, dequantize_rows,
                               quantize_rows)
 from repro.core.trace import resolve as resolve_tracer
@@ -257,7 +257,8 @@ def auto_chunk_rows(n: int, p: int, budget: int, cfg: StreamConfig) -> int:
 @partial(jax.jit, static_argnames=("params", "gram_fn"))
 def _chunk_features(xb, landmarks, projector, params: KernelParams, gram_fn):
     """One chunk's G rows: K(x_chunk, landmarks) @ projector, fused under jit."""
-    return gram_fn(xb, landmarks, params) @ projector
+    return jnp.dot(gram_fn(xb, landmarks, params), projector,
+                   precision=HIGHEST)
 
 
 @partial(jax.jit, static_argnames=("params", "group", "gram_q8_fn"))
@@ -266,7 +267,8 @@ def _chunk_features_q8(vals, scales, landmarks, projector,
     """One chunk's G rows from the int8 wire: the H2D copy shipped int8
     values + the compact scale table, and the gram kernel dequantises fused
     (no fp32 x chunk ever materialises on device)."""
-    return gram_q8_fn(vals, scales, landmarks, params, group=group) @ projector
+    return jnp.dot(gram_q8_fn(vals, scales, landmarks, params, group=group),
+                   projector, precision=HIGHEST)
 
 
 def default_gram_q8_fn() -> Callable:

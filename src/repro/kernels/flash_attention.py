@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -89,7 +87,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, bq: int = 256,
             pltpu.VMEM((bq, 1), jnp.float32),       # normalizer
             pltpu.VMEM((bq, D), jnp.float32),       # output accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

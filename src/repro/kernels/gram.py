@@ -25,8 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-from repro.core.kernel_fn import KernelParams
+from repro.core.kernel_fn import HIGHEST, KernelParams
 
 
 def _gram_kernel(x_ref, z_ref, o_ref, acc_ref, xsq_ref, zsq_ref, *,
@@ -42,7 +41,8 @@ def _gram_kernel(x_ref, z_ref, o_ref, acc_ref, xsq_ref, zsq_ref, *,
     x = x_ref[...]  # (tn, tp)
     z = z_ref[...]  # (tm, tp)
     acc_ref[...] += jax.lax.dot_general(
-        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, z, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     if params.kind == "rbf":  # norms only needed for the RBF epilogue
         xsq_ref[...] += jnp.sum(x * x, axis=1, keepdims=True)
         zsq_ref[...] += jnp.sum(z * z, axis=1, keepdims=True).T
@@ -95,7 +95,7 @@ def gram_pallas(x: jnp.ndarray, z: jnp.ndarray, params: KernelParams,
             pltpu.VMEM((tn, 1), jnp.float32),    # ||x_i||^2
             pltpu.VMEM((1, tm), jnp.float32),    # ||z_j||^2
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, z)
@@ -129,7 +129,8 @@ def _gram_kernel_q8(x_ref, sx_ref, zx_ref, z_ref, o_ref, acc_ref, xsq_ref,
     x = x_ref[...].astype(jnp.float32) * sx_ref[...] + zx_ref[...]
     z = z_ref[...]  # (tm, tp), fp32 (landmarks stay device-resident)
     acc_ref[...] += jax.lax.dot_general(
-        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, z, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
     if params.kind == "rbf":
         xsq_ref[...] += jnp.sum(x * x, axis=1, keepdims=True)
         zsq_ref[...] += jnp.sum(z * z, axis=1, keepdims=True).T
@@ -191,7 +192,7 @@ def gram_pallas_q8(x_q8: jnp.ndarray, sx: jnp.ndarray, zx: jnp.ndarray,
             pltpu.VMEM((tn, 1), jnp.float32),    # ||x_i||^2
             pltpu.VMEM((1, tm), jnp.float32),    # ||z_j||^2
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q8, sx, zx, z)

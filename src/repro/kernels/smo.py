@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 Q_FLOOR = 1e-12
 
 
@@ -46,7 +44,7 @@ def _smo_kernel(g_ref, y_ref, c_ref, q_ref, alpha_ref, unch_ref, w_ref,
     @pl.when(i == 0)
     def _init():
         w_s[...] = w_ref[...]
-        viol_s[0, 0] = 0.0
+        viol_s[...] = jnp.zeros_like(viol_s)
 
     # copy this tile's alpha / counters into the output block, update in place
     alpha_out[...] = alpha_ref[...]
@@ -82,16 +80,16 @@ def _smo_kernel(g_ref, y_ref, c_ref, q_ref, alpha_ref, unch_ref, w_ref,
         changed = jnp.abs(delta) > 0.0
         u_new = jnp.where(changed, 0, u + 1)
         unch_out[pl.ds(r, 1), :] = jnp.where(active, u_new, u)
-        viol_i = jnp.where(active, jnp.abs(pg), 0.0)[0, 0]
-        return jnp.maximum(viol, viol_i)
+        return jnp.maximum(viol, jnp.where(active, jnp.abs(pg), 0.0))
 
-    viol = jax.lax.fori_loop(0, tn, body, viol_s[0, 0])
-    viol_s[0, 0] = viol
+    # The running violation stays a (1, 1) vector: Mosaic has no scalar
+    # loads or stores on VMEM refs.
+    viol_s[...] = jax.lax.fori_loop(0, tn, body, viol_s[...])
 
     @pl.when(i == n_blocks - 1)
     def _fini():
         w_out[...] = w_s[...]
-        viol_out[0, 0] = viol_s[0, 0]
+        viol_out[...] = viol_s[...]
 
 
 @functools.partial(
@@ -140,7 +138,7 @@ def smo_epoch_pallas(G, y, c, q, alpha, unchanged, w, *,
             pltpu.VMEM((1, B), jnp.float32),   # w scratchpad (the SM trick)
             pltpu.VMEM((1, 1), jnp.float32),   # running max violation
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(G, y, c, q, alpha, unchanged, w)

@@ -30,7 +30,6 @@ import traceback
 
 import jax
 
-from repro.compat import set_mesh
 from repro.analysis.hlo import collective_stats
 from repro.configs import ARCH_IDS, get_config
 from repro.launch import specs as S
@@ -57,7 +56,7 @@ def lower_combo(cfg, shape, mesh, *, unroll: bool):
     """Lower the right step for `shape.mode`; returns (lowered, n_groups)."""
     from repro.models.model import _layout
     B = shape.global_batch
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_sds, _ = S.param_specs(cfg, mesh)
         if shape.mode == "train":
             opt = get_optimizer(cfg.optimizer)

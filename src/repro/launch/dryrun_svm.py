@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
 from repro.analysis.hlo import collective_stats
 from repro.core.distributed import stage1_gram_sharded, stage1_project_sharded
 from repro.core.dual_solver import SolverConfig, TaskBatch, solve_batch
@@ -59,7 +58,7 @@ def run(multi_pod: bool, n: int, budget: int, p: int, task_rows: int,
               f"temp={ma.temp_size_in_bytes/2**30:.2f}GiB "
               f"flops={recs[name]['cost'].get('flops', 0):.3e}", flush=True)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         x_sds = jax.ShapeDtypeStruct((n, p), jnp.float32,
                                      sharding=NamedSharding(mesh, P(rows, None)))
         lm_sds = jax.ShapeDtypeStruct((budget, p), jnp.float32,

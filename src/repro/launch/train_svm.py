@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.configs import get_config
 from repro.core import KernelParams, LPDSVM, median_gamma
 from repro.models import init_model
@@ -377,6 +378,7 @@ def main():
         ap.error(f"--shard-rows must be >= 1, got {args.shard_rows}")
     if args.spill_g and not args.shard_dir:
         ap.error("--spill-g requires --shard-dir")
+    enable_compilation_cache()
 
     stream_config = None
     # An explicit chunk/tile size or wire dtype with no budget is a request

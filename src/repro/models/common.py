@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
-
 # ---------------------------------------------------------------------------
 # logical -> physical axis resolution
 # ---------------------------------------------------------------------------
@@ -152,8 +150,8 @@ def shard(x, *logical):
     rematerialization (replicate + reshard), which is both a memory and a
     collective disaster.
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.shape:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.shape:
         return x
     resolve = logical_to_physical(mesh.axis_names)
     spec = resolve(tuple(logical))

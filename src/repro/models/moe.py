@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh, shard_map
 from repro.configs.base import ModelConfig
 from repro.models.common import dense_init, logical_to_physical
 
@@ -276,8 +275,8 @@ def moe_ffn(params, cfg: ModelConfig, x, act, *, strategy: str = "local",
     if strategy == "local":
         return moe_ffn_local(params, cfg, x, act)
 
-    mesh = get_abstract_mesh()
-    assert mesh is not None and "model" in mesh.axis_names, "needs a mesh"
+    mesh = jax.sharding.get_abstract_mesh()
+    assert "model" in mesh.axis_names, "needs a mesh"
     M = mesh.shape["model"]
     if cfg.n_experts % M != 0:
         return moe_ffn_local(params, cfg, x, act)
@@ -297,7 +296,7 @@ def moe_ffn(params, cfg: ModelConfig, x, act, *, strategy: str = "local",
         C = max(8, -(-int(T_loc * cfg.top_k / M * cfg.capacity_factor)) // 8 * 8)
         body = functools.partial(_moe_a2a_block, cfg=cfg, M=M, C=C, act=act,
                                  fsdp_axis="data", all_axes=all_axes)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(token_spec, P(None, None), wg_spec, wg_spec, wd_spec),
             out_specs=(token_spec, P()), check_vma=False)
@@ -320,7 +319,7 @@ def moe_ffn(params, cfg: ModelConfig, x, act, *, strategy: str = "local",
                 _moe_replicated_block, cfg=cfg, M=M, act=act,
                 fsdp_axis="data", all_axes=all_axes,
                 reduce_axes=tuple(reduce_axes))
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(token_spec, P(None, None), wg_spec, wg_spec, wd_spec),
             out_specs=(token_spec, P()), check_vma=False)

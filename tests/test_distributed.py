@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import set_mesh
 from repro.core import KernelParams, SolverConfig, compute_factor
 from repro.core.distributed import (replicate, solve_tasks_sharded,
                                     stage1_gram_sharded)
@@ -70,7 +69,7 @@ def test_moe_sharded_strategies_match_local(rng, mesh):
     x = jnp.asarray(rng.normal(size=(T, 32)), jnp.float32)
     act = activation(cfg.act)
     out_local, aux_local = moe_ffn(params, cfg, x, act, strategy="local")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_a2a, aux_a2a = jax.jit(
             lambda p, x: moe_ffn(p, cfg, x, act, strategy="a2a"))(params, x)
         from jax.sharding import PartitionSpec as P

@@ -22,13 +22,12 @@ def run_sub(code: str, n_dev: int = 8, timeout: int = 900) -> str:
 def test_moe_a2a_and_replicated_match_local():
     run_sub(r"""
 import dataclasses, jax, numpy as np, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs import get_config
 from repro.models.common import activation
 from repro.models.moe import init_moe, moe_ffn
 
-mesh = make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 cfg = get_config("jamba-v0.1-52b", reduced=True)
 cfg = dataclasses.replace(cfg, n_experts=8, top_k=2, moe_d_ff=64, d_model=32,
                           capacity_factor=8.0)
@@ -37,7 +36,7 @@ rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
 act = activation(cfg.act)
 out_local, aux_local = moe_ffn(params, cfg, x, act, strategy="local")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     out_a2a, aux_a2a = jax.jit(lambda p, x: moe_ffn(p, cfg, x, act, strategy="a2a"))(params, x)
     out_rep, aux_rep = jax.jit(lambda p, x: moe_ffn(p, cfg, x, act, strategy="replicated", token_spec=P(None, None)))(params, x)
 np.testing.assert_allclose(np.asarray(out_a2a), np.asarray(out_local), rtol=2e-3, atol=2e-3)
@@ -53,7 +52,7 @@ print("MOE-OK")
 def test_sharded_train_step_matches_single_device():
     run_sub(r"""
 import jax, numpy as np, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.launch.steps import make_train_step
 from repro.models import init_model
@@ -71,8 +70,8 @@ batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 64)), jnp.int
 step0 = jax.jit(make_train_step(cfg, opt, global_batch=8))
 _, _, m0 = step0(params, st, batch)
 
-mesh = make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
-with set_mesh(mesh):
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+with jax.set_mesh(mesh):
     step1 = jax.jit(make_train_step(cfg, opt, mesh, global_batch=8))
     _, _, m1 = step1(params, st, batch)
 diff = abs(float(m0["loss"]) - float(m1["loss"]))
@@ -84,13 +83,13 @@ print("TRAIN-OK", float(m0["loss"]), float(m1["loss"]))
 def test_task_farm_on_8_devices():
     run_sub(r"""
 import jax, numpy as np, jax.numpy as jnp
-from repro.compat import AxisType, make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.core import KernelParams, SolverConfig, compute_factor
 from repro.core.distributed import solve_tasks_sharded
 from repro.core.dual_solver import solve_batch
 from repro.core.ovo import build_ovo_tasks
 
-mesh = make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 rng = np.random.default_rng(0)
 x = rng.normal(size=(240, 4)).astype(np.float32)
 y = (x[:, 0] > 0).astype(int) + 2 * (x[:, 1] > 0).astype(int)   # 4 classes

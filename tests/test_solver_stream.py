@@ -441,11 +441,10 @@ def test_polish_final_level_streams_int8():
 
 
 def test_streamed_mesh_single_device_matches():
-    from repro.compat import make_mesh
     from repro.core import solve_tasks_streamed_mesh
     G, tasks, _ = _problem(n=240, budget=48)
     cfg = SolverConfig(tol=1e-2, max_epochs=120)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     res = solve_tasks_streamed_mesh(mesh, G, tasks, cfg,
                                     stream_config=StreamConfig(tile_rows=64))
     mono = solve_batch(jnp.asarray(G), tasks, cfg)

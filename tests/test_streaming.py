@@ -247,7 +247,6 @@ def test_disjoint_chunk_streams_over_devices():
     env["PYTHONPATH"] = src
     code = r"""
 import jax, numpy as np
-from repro.compat import make_mesh
 from repro.core import KernelParams, compute_factor
 from repro.core.distributed import compute_factor_streamed_mesh, stream_factor_over_mesh
 from repro.core.streaming import StreamConfig
@@ -256,7 +255,7 @@ assert len(jax.devices()) == 4
 kp = KernelParams("rbf", gamma=0.5)
 x = np.random.default_rng(0).normal(size=(403, 7)).astype(np.float32)
 mono = compute_factor(x, kp, 64)
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"))
 out = stream_factor_over_mesh(mesh, x, mono.landmarks, mono.projector, kp,
                               chunk_rows=37)
 np.testing.assert_allclose(out, np.asarray(mono.G), rtol=1e-5, atol=1e-5)
