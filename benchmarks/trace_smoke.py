@@ -5,7 +5,7 @@ Three assertions, CI-fatal on regression:
 
   1. **Coverage** — one streamed multi-class fit under a `Tracer` exports
      Chrome-trace JSON that loads back with >= 1 span in every core
-     category (read / h2d / kernel / drain / epoch): an instrumentation
+     category (read / h2d / dispatch / d2h / epoch): an instrumentation
      hole in a hot path fails here, not in a production trace.
   2. **No-op** — a live but uninstalled spy tracer records ZERO events
      across the same fit: the default path really is the `NULL` fast path.
@@ -30,7 +30,7 @@ import numpy as np
 from benchmarks.common import emit
 
 OUT_PATH = os.environ.get("TRACE_SMOKE_JSON", "/tmp/trace_smoke.json")
-REQUIRED_CATEGORIES = ("read", "h2d", "kernel", "drain", "epoch")
+REQUIRED_CATEGORIES = ("read", "h2d", "dispatch", "d2h", "epoch")
 
 # disabled begin/end vs bare perf_counter pair; generous bound — this guards
 # against accidentally routing the NULL path through recording, not against
@@ -68,7 +68,7 @@ def run() -> None:
     missing = [c for c in REQUIRED_CATEGORIES if not by_cat.get(c)]
     assert not missing, f"trace missing categories {missing}: {by_cat}"
     summary = tr.summary()
-    assert "overlap" in summary and "rows/s" in summary
+    assert "effective H2D" in summary and "rows/s" in summary
     emit("trace_smoke_coverage", fit_s * 1e6,
          f"{len(spans)} spans over {len(by_cat)} categories -> {OUT_PATH}")
 
@@ -85,8 +85,8 @@ def run() -> None:
     def loop_null():
         t = 0.0
         for _ in range(reps):
-            t0 = NULL.begin()
-            t += NULL.end("h2d", "put", t0)
+            t0 = NULL.begin("h2d", "put")
+            t += NULL.end(t0)
         return t
 
     def loop_bare():

@@ -277,12 +277,12 @@ def grid_search(
                 stream_config,
                 checkpoint_dir=os.path.join(ck, f"gamma{gi}") if ck else None,
                 shard_dir=os.path.join(sd, f"gamma{gi}") if sd else None)
-        t0 = tr.begin()
+        t0 = tr.begin("cv", "stage1_factor")
         factor = compute_factor(x, kp, budget,
                                 key=jax.random.PRNGKey(seed), gram_fn=gram_fn,
                                 stream=stream, stream_config=g_cfg)
         wait_for_factor(factor.G)
-        t_stage1 += tr.end("cv", "stage1_factor", t0, gamma=float(gamma))
+        t_stage1 += tr.end(t0, gamma=float(gamma))
 
         warm = warm_first_c if warm_start_gamma else None
         use_farm = False
@@ -299,7 +299,7 @@ def grid_search(
             # pair) cell of this gamma — the C-ladder runs inside the
             # engine, so the epoch budget covers the whole ladder (the +1
             # per level pays each seeded cell's w0-accumulation pass).
-            t0 = tr.begin()
+            t0 = tr.begin("cv", "grid_farm")
             FP = folds * len(pairs)
             farm_cfg = dataclasses.replace(
                 config, max_epochs=config.max_epochs * len(Cs) + len(Cs))
@@ -307,8 +307,7 @@ def grid_search(
                 factor.G, gtasks, farm_cfg, stream_config=g_cfg,
                 chain_next=chain, return_stats=True)
             wait_for_factor(res.w)
-            dt = tr.end("cv", "grid_farm", t0, gamma=float(gamma),
-                        cells=gtasks.n_tasks)
+            dt = tr.end(t0, gamma=float(gamma), cells=gtasks.n_tasks)
             t_stage2 += dt
             cell_sec[gi, :] = dt / len(Cs)
             n_solved += gtasks.n_tasks
@@ -327,7 +326,7 @@ def grid_search(
 
         val_sets = _fold_val_sets(factor, labels, val_masks)
         for ci, C in enumerate(Cs):
-            t0 = tr.begin()
+            t0 = tr.begin("cv", "grid_cell")
             tasks, _ = build_cv_tasks(labels, n_classes, C, val_masks,
                                       warm=warm if warm_start else None)
             c_cfg = g_cfg
@@ -338,8 +337,7 @@ def grid_search(
             res = _solve_routed(factor, tasks, config, solve_fn,
                                 stream, c_cfg, polish_schedule)
             wait_for_factor(res.w)
-            dt = tr.end("cv", "grid_cell", t0, gamma=float(gamma),
-                        C=float(C))
+            dt = tr.end(t0, gamma=float(gamma), C=float(C))
             t_stage2 += dt
             cell_sec[gi, ci] = dt
             n_solved += tasks.n_tasks

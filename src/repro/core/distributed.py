@@ -212,14 +212,14 @@ class _DeviceWorkers:
     def _loop(self, q, name):
         tr = self._tr
         while True:
-            t0 = tr.begin()
+            t0 = tr.begin("queue", "worker_idle")
             fn = q.get()
             try:
                 if fn is None:
                     self._last[name] = ("exited", time.monotonic())
                     return
                 if tr.enabled:
-                    tr.end("queue", "worker_idle", t0, device=name)
+                    tr.end(t0, device=name)
                     tr.counter(f"queue_depth/{name}", q.qsize())
                 self._last[name] = ("running", time.monotonic())
                 if not self._errors:     # fail fast: drain the rest as no-ops
@@ -241,10 +241,9 @@ class _DeviceWorkers:
         tr = self._tr
         if tr.enabled and q.full():
             # Reader blocked on a full device queue — measured backpressure.
-            t0 = tr.begin()
+            t0 = tr.begin("queue", "backpressure")
             q.put(fn)
-            tr.end("queue", "backpressure", t0,
-                   device=self._names[id(engine)])
+            tr.end(t0, device=self._names[id(engine)])
         else:
             q.put(fn)
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kernel_fn import HIGHEST, KernelParams, gram
+from repro.core.trace import resolve as resolve_tracer
 
 # float32 machine epsilon is ~1.19e-7; the paper drops eigenvalues "as soon as
 # the eigenvalues fall below a threshold close to the machine precision times
@@ -143,22 +144,28 @@ def compute_factor(
             x, params, budget, key=key, eig_rtol=eig_rtol, config=cfg,
             gram_fn=gram_fn)
 
-    x = jnp.asarray(x, dtype=jnp.float32)
+    tr = resolve_tracer(getattr(stream_config, "trace", None))
+    with tr.span("h2d", "stage1_x"):
+        x = jnp.asarray(x, dtype=jnp.float32)
     n = x.shape[0]
-    landmarks = select_landmarks(x, budget, key)
-    k_mm = gram_fn(landmarks, landmarks, params)
-    projector, evals, rank = _eig_projector(k_mm, params, eig_rtol)
-    rank = int(rank)
+    with tr.span("stage1", "landmarks"):
+        landmarks = select_landmarks(x, budget, key)
+    with tr.span("stage1", "gram"):
+        k_mm = gram_fn(landmarks, landmarks, params)
+    with tr.span("stage1", "eig_projector"):
+        # int(rank) waits for the eigh: the rank is a data-dependent shape.
+        projector, evals, rank = _eig_projector(k_mm, params, eig_rtol)
+        rank = int(rank)
 
-    if compact:
-        projector = projector[:, :rank]
-
-    blocks = []
-    for start in range(0, n, block_rows):
-        xb = x[start:start + block_rows]
-        blocks.append(jnp.dot(gram_fn(xb, landmarks, params), projector,
-                              precision=HIGHEST))
-    G = jnp.concatenate(blocks, axis=0) if len(blocks) > 1 else blocks[0]
+    with tr.span("stage1", "project"):
+        if compact:
+            projector = projector[:, :rank]
+        blocks = []
+        for start in range(0, n, block_rows):
+            xb = x[start:start + block_rows]
+            blocks.append(jnp.dot(gram_fn(xb, landmarks, params), projector,
+                                  precision=HIGHEST))
+        G = jnp.concatenate(blocks, axis=0) if len(blocks) > 1 else blocks[0]
 
     return LowRankFactor(
         G=G, landmarks=landmarks, projector=projector, eigvals=evals,

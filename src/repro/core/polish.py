@@ -285,7 +285,7 @@ def solve_polished(
     for li in keep:
         frac = schedule.fractions[li]
         final = frac >= 1.0
-        t0 = tr.begin()
+        t0 = tr.begin("polish", f"level_{li}")
         sstats = None
         if final:
             tasks_l = TaskBatch(idx=tasks.idx, y=tasks.y, c=tasks.c,
@@ -370,9 +370,8 @@ def solve_polished(
                     # quantity the tolerance annealing drives toward zero
                     gaps[t] = task_duality_gap(G_np[idx_l[t, :k]], y_l[t, :k],
                                                c_l[t, :k], a_np[t][:k])
-        dt = tr.end("polish", f"level_{li}", t0, fraction=float(frac),
-                    tol=float(cfg_l.tol), rows=n_rows_l,
-                    streamed=streamed, row_visits=visits)
+        dt = tr.end(t0, fraction=float(frac), tol=float(cfg_l.tol),
+                    rows=n_rows_l, streamed=streamed, row_visits=visits)
         trace.levels.append(PolishLevelStats(
             fraction=frac, tol=cfg_l.tol, n_rows=n_rows_l, n_pad=n_pad_l,
             streamed=streamed, epochs=np.asarray(res_l.epochs),

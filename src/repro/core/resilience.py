@@ -63,7 +63,7 @@ _CARRY_SUM = ("bytes_h2d", "bytes_d2h", "bytes_g", "bytes_scales",
               "bytes_put", "bytes_hit", "bytes_miss", "blocks_streamed",
               "rows_streamed", "kernel_calls", "coord_visits", "cache_hits",
               "cache_misses", "cache_evictions", "cache_resident_bytes",
-              "full_passes")
+              "full_passes", "h2d_puts", "d2h_syncs")
 _CARRY_SUM_F = ("put_seconds", "drain_seconds", "seconds")
 _CARRY_MAX = ("epochs", "prefetch_final")
 _CARRY_LIST = ("epoch_bytes", "epoch_hit_bytes", "epoch_miss_bytes",

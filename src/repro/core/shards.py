@@ -316,10 +316,10 @@ class ShardWriter:
         buffers, digest = _pack_shard(values, scales, labels, self.dtype,
                                       self.group)
         path = os.path.join(self.directory, shard_name(i))
-        t0 = self.trace.begin()
+        t0 = self.trace.begin("disk", "shard_write")
         nbytes = _fsync_write(path, buffers)
-        self.stats.write_seconds += self.trace.end(
-            "disk", "shard_write", t0, shard=i, bytes=nbytes)
+        self.stats.write_seconds += self.trace.end(t0, shard=i,
+                                                   bytes=nbytes)
         self.stats.shards_written += 1
         self.stats.bytes_written += nbytes
         self._shards.append({"name": shard_name(i), "rows": int(count),
@@ -463,11 +463,11 @@ class ShardStore:
             try:
                 _fault_check("shard_read", shard=i)
                 _fault_check("shard_corrupt", shard=i, path=path)
-                t0 = self.trace.begin()
+                t0 = self.trace.begin("disk", "shard_read")
                 with open(path, "rb") as f:
                     buf = f.read()
                 self.stats.read_seconds += self.trace.end(
-                    "disk", "shard_read", t0, shard=i, bytes=len(buf))
+                    t0, shard=i, bytes=len(buf))
                 self.stats.shards_read += 1
                 self.stats.bytes_read += len(buf)
                 if attempt:

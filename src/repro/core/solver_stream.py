@@ -76,7 +76,7 @@ import contextlib
 import dataclasses
 import math
 import time
-from functools import partial
+from functools import partial, wraps
 from typing import Callable, List, Optional, Sequence
 
 import jax
@@ -374,6 +374,9 @@ class Stage2StreamStats:
     block_dtype: str = "f32"
     n_devices: int = 1
     bytes_put: int = 0                # physical per-device DMA bytes
+    h2d_puts: int = 0                 # device_puts made by engines' `_h2d`
+    d2h_syncs: int = 0                # blocking device-to-host reads (block
+                                      # drains, violations, the result W)
     put_seconds: float = 0.0          # host time inside H2D puts
     drain_seconds: float = 0.0        # host time blocked on result fetches
     prefetch_final: int = 0           # queue depth after autotune
@@ -396,8 +399,9 @@ class Stage2StreamStats:
     @property
     def overlap_efficiency(self) -> float:
         """Stall-free fraction of the wall clock: 1 minus the share spent
-        blocked in puts/drains, clamped to [0, 1].  The trace-level
-        `Tracer.overlap_efficiency` is the per-span timeline analogue."""
+        blocked in puts/drains, clamped to [0, 1].  Which of those stalls
+        left the device idle is read from a profiler trace of the mirrored
+        spans (`core/trace.py`)."""
         if self.seconds <= 0.0:
             return 0.0
         busy = (self.put_seconds + self.drain_seconds) / self.seconds
@@ -496,20 +500,18 @@ def iter_shared_blocks(G: np.ndarray, tile: int, block_dtype: str,
     tr = resolve_tracer(trace)
     for b in range(math.ceil(n / tile)):
         s, e = b * tile, min((b + 1) * tile, n)
-        t0 = tr.begin()
+        t0 = tr.begin("read", "stage_block")
         try:
             _fault_check("reader", block=b)
             gb_send = prep_block(G[s:e], tile, block_dtype, group, stage)
         except BaseException as exc:
             # Close the in-flight span before propagating so a failed run
             # still exports a valid, complete trace timeline.
-            tr.end("read", "stage_block", t0, rows=e - s, block=b,
-                   error=type(exc).__name__)
+            tr.end(t0, rows=e - s, block=b, error=type(exc).__name__)
             tr.instant("fault", "reader_error", block=b,
                        error=type(exc).__name__)
             raise
-        tr.end("read", "stage_block", t0, bytes=int(gb_send.nbytes),
-               rows=e - s, block=b)
+        tr.end(t0, bytes=int(gb_send.nbytes), rows=e - s, block=b)
         yield slice(s, e), e - s, gb_send
 
 
@@ -540,7 +542,7 @@ class _BlockPipeline:
 
     def _drain_one(self):
         items = self.inflight.popleft()
-        t0 = self.trace.begin()
+        t0 = self.trace.begin("d2h", "block_drain")
         nb = 0
         for t, take, m, a_ref, u_ref in items:
             # ``take`` addresses the window in the task-LOCAL arrays: a
@@ -550,8 +552,9 @@ class _BlockPipeline:
             self.u_r[t][take] = np.asarray(u_ref)[:m]
             self.stats.bytes_d2h += 2 * m * BYTES_F32
             nb += 2 * m * BYTES_F32
-        self.stats.drain_seconds += self.trace.end(
-            "drain", "block_drain", t0, bytes=nb, windows=len(items))
+        self.stats.d2h_syncs += 2 * len(items)
+        self.stats.drain_seconds += self.trace.end(t0, bytes=nb,
+                                                   windows=len(items))
 
 
 def _padded(vec, fill, dtype, tile):
@@ -560,6 +563,18 @@ def _padded(vec, fill, dtype, tile):
     buf = np.full((tile,), fill, dtype)
     buf[: vec.shape[0]] = vec
     return buf
+
+
+def _engine_span(fn):
+    """Wrap an engine entry point in an ``engine/<name>`` span: the host
+    bookkeeping between the engine's puts, dispatches and reads."""
+    name = fn.__name__
+
+    @wraps(fn)
+    def spanned(self, *args, **kwargs):
+        with self.trace.span("engine", name):
+            return fn(self, *args, **kwargs)
+    return spanned
 
 
 class _Stage2Engine:
@@ -735,12 +750,14 @@ class _Stage2Engine:
     def all_done(self) -> bool:
         return bool(self.done.all())
 
+    @_engine_span
     def start_epoch(self, epoch: int) -> None:
         self._epoch = epoch
         self._epoch_mark = self.stats.bytes_h2d
         self._hit_mark = self.stats.bytes_hit
         self._miss_mark = self.stats.bytes_miss
 
+    @_engine_span
     def finish_epoch(self, epoch: int) -> None:
         self.epochs_run = epoch + 1
         self.stats.epoch_bytes.append(self.stats.bytes_h2d - self._epoch_mark)
@@ -815,60 +832,65 @@ class _Stage2Engine:
                     time.sleep(delay)
                 attempt += 1
                 continue
+            self.stats.h2d_puts += 1
             if attempt:
                 self.trace.instant("recovery", "h2d_retry_ok",
                                    device=self.name, attempts=attempt)
             return out
 
     def _put_block(self, gb_send, cache_key: Optional[bytes] = None):
-        t0 = self.trace.begin()
+        t0 = self.trace.begin("h2d", "put_block")
         if isinstance(gb_send, QuantBlock):
             # int8 wire: ship values + compact scale table, dequantise fused
             # on device — a quarter of the f32 bytes crossed the bus.
             vals = self._h2d(gb_send.values)
             scales = self._h2d(gb_send.scales)
             self.stats.put_seconds += self.trace.end(
-                "h2d", "put_block", t0, bytes=int(gb_send.nbytes))
+                t0, bytes=int(gb_send.nbytes))
             self.stats.bytes_put += gb_send.nbytes
+            payload = (vals, scales, gb_send.group)
             if cache_key is not None:
                 # Pin the WIRE arrays (int8 codes + scale table, a quarter
                 # of the f32 residency); dequant stays fused per use.
-                self._cache_store(cache_key, (vals, scales, gb_send.group),
-                                  gb_send.nbytes)
-            return dequant_rows(vals, scales, gb_send.group)
+                self._cache_store(cache_key, payload, gb_send.nbytes)
+            return self._decode(payload)
         gb = self._h2d(gb_send)
         self.stats.put_seconds += self.trace.end(
-            "h2d", "put_block", t0, bytes=int(gb_send.nbytes))
+            t0, bytes=int(gb_send.nbytes))
         self.stats.bytes_put += gb_send.nbytes
         if cache_key is not None:
             # Pin the device array exactly as put (bf16 stays bf16 — the
             # upcast is re-run per use, same as the streamed path), so a
             # cached block decodes bit-identically to a shipped one.
             self._cache_store(cache_key, gb, gb_send.nbytes)
-        return _upcast32(gb) if self._bf16 else gb
+        return self._decode(gb)
 
     def _cache_store(self, key: bytes, payload, nbytes: int) -> None:
         if self.cache is not None and self.cache.put(key, payload, nbytes):
             self.stats.cache_resident_bytes = self.cache.peak_resident_bytes
 
-    def _decode_cached(self, payload):
-        """Re-run the per-use decode step on a pinned payload — the SAME ops
-        the miss path applies after its H2D put, so hit and miss blocks are
+    def _decode(self, payload):
+        """The per-use decode step of a wire block, just put or pinned —
+        the SAME ops on the hit and the miss path, so both are
         bit-identical inputs to the epoch kernel."""
         if isinstance(payload, tuple):
             vals, scales, group = payload
-            return dequant_rows(vals, scales, group)
-        return _upcast32(payload) if self._bf16 else payload
+            with self.trace.span("dispatch", "dequant"):
+                return dequant_rows(vals, scales, group)
+        if not self._bf16:
+            return payload
+        with self.trace.span("dispatch", "dequant"):
+            return _upcast32(payload)
 
     def _put_vec(self, vec, fill, dtype, length):
-        t0 = self.trace.begin()
+        t0 = self.trace.begin("h2d", "put_vec")
         b = self._h2d(_padded(np.asarray(vec), fill, dtype, length))
-        self.stats.put_seconds += self.trace.end(
-            "h2d", "put_vec", t0, bytes=int(b.nbytes))
+        self.stats.put_seconds += self.trace.end(t0, bytes=int(b.nbytes))
         self.stats.bytes_h2d += b.nbytes
         self.stats.bytes_put += b.nbytes
         return b
 
+    @_engine_span
     def feed_block(self, sel, cnt, gb_send) -> None:
         """Process one shared-pass block handed over by the driver's reader.
         The G bytes were staged (and accounted) once by the reader; only this
@@ -889,11 +911,13 @@ class _Stage2Engine:
                 rlb = self._put_vec(rl, 0, np.int32, wl)
                 ab = self._put_vec(self.a_r[t][lo:hi], 0.0, np.float32, wl)
                 yb = self._put_vec(self.y_r[t][lo:hi], 1.0, np.float32, wl)
-                self.w[t] = _accum_w(self.w[t], _gather_rows(gb, rlb), ab, yb)
+                with self.trace.span("dispatch", "accum_w"):
+                    self.w[t] = _accum_w(self.w[t], _gather_rows(gb, rlb),
+                                         ab, yb)
                 self.stats.kernel_calls += 1
         if self._kind == "init" or not self._live:
             return
-        qb = _row_sq(gb)
+        qb = self._row_sq(gb)
         base = sel.start
         items = []
         for t in self._live:
@@ -904,6 +928,10 @@ class _Stage2Engine:
             items.append(self._sweep_window(gb, qb, t, slice(lo, hi), rl,
                                             full=(self._kind == "full")))
         self.pipe.push(items)
+
+    def _row_sq(self, gb):
+        with self.trace.span("dispatch", "row_sq"):
+            return _row_sq(gb)
 
     def _sweep_window(self, gb, qb, t, take, rl, *, full: bool):
         """Run the epoch kernel over ONE task's window of a staged block:
@@ -916,23 +944,27 @@ class _Stage2Engine:
         m = len(rl)
         wl = _win_pad(m)
         rlb = self._put_vec(rl, 0, np.int32, wl)
-        gw, qw = _window(gb, qb, rlb)
+        with self.trace.span("dispatch", "window"):
+            gw, qw = _window(gb, qb, rlb)
         ab = self._put_vec(self.a_r[t][take], 0.0, np.float32, wl)
         yb = self._put_vec(self.y_r[t][take], 1.0, np.float32, wl)
         cb = self._put_vec(self.c_r[t][take], 0.0, np.float32, wl)
         ub = self._put_vec(self.u_r[t][take], 0, np.int32, wl)
-        t0 = self.trace.begin()
+        # The span times the enqueue; the kernel runs asynchronously and
+        # shows on the device's own line of a profiler trace.
+        t0 = self.trace.begin("dispatch", "smo")
         a2, u2, w2, viol = self.epoch_fn(
             gw, yb, cb, qw, ab, ub, self.w[t],
             full_pass=full, shrink_k=self.shrink_k)
         self.w[t] = w2
-        self.trace.end("kernel", "sweep_window", t0, rows=m, task=t)
+        self.trace.end(t0, rows=m, task=t)
         self.stats.kernel_calls += 1
         self.stats.coord_visits += m
         if full:
             self._viol[t].append(viol)
         return (t, take, m, a2, u2)
 
+    @_engine_span
     def end_pass(self) -> None:
         self.pipe.flush()
         newly = self._init_live
@@ -943,8 +975,13 @@ class _Stage2Engine:
                 # Empty generators (a task with no real rows, or none inside
                 # this shard's blocks) converge trivially — exactly what the
                 # old inert-padded sweep reported for them.
-                v = max((float(np.asarray(r)) for r in self._viol[t]),
-                        default=0.0)
+                vals = []
+                for r in self._viol[t]:
+                    t0 = self.trace.begin("d2h", "violation")
+                    vals.append(float(np.asarray(r)))
+                    self.trace.end(t0)
+                self.stats.d2h_syncs += len(vals)
+                v = max(vals, default=0.0)
                 self.violation[t] = v
                 if v < self.config.tol:
                     self.done[t] = True
@@ -984,7 +1021,7 @@ class _Stage2Engine:
 
         Cheap epochs then stream only rows active for at least one
         unconverged task — shrinking cuts H2D bytes, not just FLOPs."""
-        t0 = self.trace.begin()
+        t0 = self.trace.begin("compact", "recompact")
         self.act, self.act_G, self.act_q = None, None, None
         self._cw = {}
         self._act_keys = self._act_sizes = None
@@ -1064,8 +1101,7 @@ class _Stage2Engine:
                 self.trace.instant("cache", "invalidate",
                                    evictions=self.cache.evictions)
         self.trace.end(
-            "compact", "recompact", t0,
-            union=int(len(self.act)) if self.act is not None else self.n,
+            t0, union=int(len(self.act)) if self.act is not None else self.n,
             tasks=len(live2))
 
     # ----------------------------------------------------- compacted epochs
@@ -1103,6 +1139,7 @@ class _Stage2Engine:
             out.append(qb if e - s == tile else pad_quant_block(qb, tile))
         return out
 
+    @_engine_span
     def run_cheap_epoch(self) -> None:
         """One engine-local non-full epoch over the shard's own compacted
         active-row union (the driver only calls this when `act` is set; an
@@ -1125,12 +1162,13 @@ class _Stage2Engine:
                 self.stats.cache_hits += 1
                 self.trace.instant("cache", "hit", bytes=int(ent.nbytes),
                                    block=b)
-                gb = self._decode_cached(ent.payload)
+                gb = self._decode(ent.payload)
             else:
-                gb_send = (self.act_q[b] if self.act_q is not None
-                           else prep_block(self.act_G[s:e], tile,
-                                           self.cfg.block_dtype, self._group,
-                                           self._stage))
+                with self.trace.span("read", "stage_compacted"):
+                    gb_send = (self.act_q[b] if self.act_q is not None
+                               else prep_block(self.act_G[s:e], tile,
+                                               self.cfg.block_dtype,
+                                               self._group, self._stage))
                 self.stats.bytes_h2d += gb_send.nbytes
                 self.stats.bytes_g += gb_send.nbytes
                 self.stats.bytes_miss += gb_send.nbytes
@@ -1143,7 +1181,7 @@ class _Stage2Engine:
                     self.trace.instant("cache", "miss",
                                        bytes=int(gb_send.nbytes), block=b)
                 gb = self._put_block(gb_send, cache_key=key)
-            qb = _row_sq(gb)
+            qb = self._row_sq(gb)
             items = []
             for t in self._live:
                 cw = self._cw.get(t)
@@ -1166,15 +1204,17 @@ class _Stage2Engine:
     def result(self):
         """Assemble this shard's `SolveResult` (host numpy, same layout as
         `solve_batch`) and its per-device stats record."""
-        t0 = self.trace.begin()
+        t0 = self.trace.begin("d2h", "result")
         W = (np.stack([np.asarray(wt) for wt in self.w]) if self.T
              else np.zeros((0, self.rank), np.float32))
+        self.trace.end(t0, bytes=int(W.nbytes), tasks=self.T)
         self.stats.bytes_d2h += W.nbytes
+        self.stats.d2h_syncs += self.T
+        t0 = self.trace.begin("scatter", "result")
         alpha = np.zeros_like(self.a0_loc)
         for t in range(self.T):
             alpha[t][self.scat[t]] = self.a_r[t]
-        self.trace.end("scatter", "result", t0,
-                       bytes=int(W.nbytes + alpha.nbytes), tasks=self.T)
+        self.trace.end(t0, bytes=int(alpha.nbytes), tasks=self.T)
         asum = (np.array([self.a_r[t].sum() for t in range(self.T)],
                          np.float32) if self.T
                 else np.zeros((0,), np.float32))
@@ -1272,16 +1312,16 @@ def drive_streamed_engines(engines: Sequence[_Stage2Engine], G, config:
             live = [e for e in engines if not e.all_done]
             if not live:
                 break
-            for e in live:
-                e.start_epoch(epoch)
-            if tr.enabled:
-                te0 = tr.begin()
-                cv0 = sum(e.stats.coord_visits for e in live)
             full = ((epoch % period == 0) or not config.shrink
                     or any(e.wants_full for e in live))
             # ^ freshly seeded C-ladder successors need a full-coverage pass
             #   for their w0 accumulation — promote rather than let them
             #   idle until the next scheduled full pass
+            if tr.enabled:
+                te0 = tr.begin("epoch", "full" if full else "cheap")
+                cv0 = sum(e.stats.coord_visits for e in live)
+            for e in live:
+                e.start_epoch(epoch)
             if full:
                 reader.epoch_bytes.append(shared_pass(live, "full"))
                 reader.full_passes += 1
@@ -1322,7 +1362,7 @@ def drive_streamed_engines(engines: Sequence[_Stage2Engine], G, config:
     return reader
 
 
-def _trace_epoch(tr, t0: float, epoch: int, kind: str,
+def _trace_epoch(tr, t0, epoch: int, kind: str,
                  live: Sequence[_Stage2Engine], reader: Stage2StreamStats,
                  cv0: int) -> None:
     """Close the driver's per-epoch span: attrs aggregate the epoch's
@@ -1344,10 +1384,7 @@ def _trace_epoch(tr, t0: float, epoch: int, kind: str,
                  devices=len(live))
     if viols.size:
         attrs["viol"] = float(viols.max())
-    tr.end("epoch", f"epoch_{epoch}", t0, **attrs)
-    tr.counter("stage2/epoch_bytes", eb)
-    tr.counter("stage2/active_rows", act)
-    tr.counter("stage2/row_visits", rows)
+    tr.end(t0, **attrs)
 
 
 def _elementwise_sum(lists: Sequence[Sequence[int]]) -> List[int]:
@@ -1393,6 +1430,8 @@ def merge_stream_stats(reader: Stage2StreamStats,
         out.bytes_scales += s.bytes_scales
         out.bytes_put += s.bytes_put
         out.bytes_d2h += s.bytes_d2h
+        out.h2d_puts += s.h2d_puts
+        out.d2h_syncs += s.d2h_syncs
         out.blocks_streamed += s.blocks_streamed
         out.rows_streamed += s.rows_streamed
         out.kernel_calls += s.kernel_calls
@@ -1464,8 +1503,9 @@ def solve_batch_streamed(
         G = np.asarray(G, np.float32)
     n, rank = G.shape
     tile = auto_tile_rows(n, rank, tasks.n_tasks, cfg)
-    eng = _Stage2Engine(G, tasks, config, cfg, epoch_fn=epoch_fn,
-                        device=device, tile=tile, chain_next=chain_next)
+    with resolve_tracer(cfg.trace).span("engine", "build"):
+        eng = _Stage2Engine(G, tasks, config, cfg, epoch_fn=epoch_fn,
+                            device=device, tile=tile, chain_next=chain_next)
     guard = None
     if cfg.checkpoint_dir:
         from repro.core.resilience import (StreamGuard, g_fingerprint,

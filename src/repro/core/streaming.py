@@ -210,8 +210,9 @@ class Stage1StreamStats:
     @property
     def overlap_efficiency(self) -> float:
         """Stall-free fraction of the wall clock: 1 minus the share spent
-        blocked in puts/drains, clamped to [0, 1].  The trace-level
-        `Tracer.overlap_efficiency` is the per-span timeline analogue."""
+        blocked in puts/drains, clamped to [0, 1].  Which of those stalls
+        left the device idle is read from a profiler trace of the mirrored
+        spans (`core/trace.py`)."""
         if self.seconds <= 0.0:
             return 0.0
         busy = (self.put_seconds + self.drain_seconds) / self.seconds
@@ -365,18 +366,16 @@ def stream_factor_blocks(
 
     def drain_one():
         s, e, gb = inflight.popleft()
-        t0 = tr.begin()
+        t0 = tr.begin("d2h", "stage1_fetch")
         out[s:e] = np.asarray(gb)   # blocks on this chunk only
-        st.drain_seconds += tr.end("drain", "stage1_fetch", t0,
-                                   bytes=int(gb.nbytes), rows=e - s)
+        st.drain_seconds += tr.end(t0, bytes=int(gb.nbytes), rows=e - s)
         if progress is not None:
             progress.mark(s, e, flush=g_flush)
 
     def put(a, d):
-        t0 = tr.begin()
+        t0 = tr.begin("h2d", "stage1_put")
         b = jnp.asarray(a) if d is None else jax.device_put(a, d)
-        st.put_seconds += tr.end("h2d", "stage1_put", t0,
-                                 bytes=int(a.nbytes))
+        st.put_seconds += tr.end(t0, bytes=int(a.nbytes))
         st.bytes_h2d += a.nbytes
         return b
 
@@ -412,23 +411,23 @@ def stream_factor_blocks(
             if pre:
                 vals, scales, grp = xb.values, xb.scales, xb.group
             else:
-                t0 = tr.begin()
+                t0 = tr.begin("encode", "stage1_quant")
                 vals, scales = quantize_rows(xb, quant_group_rows,
                                              symmetric=True)
-                tr.end("encode", "stage1_quant", t0, rows=xb.shape[0],
+                tr.end(t0, rows=xb.shape[0],
                        bytes=int(vals.nbytes + scales.nbytes))
                 grp = quant_group_rows
             st.bytes_scales += scales.nbytes
             bv, bs = put(vals, d), put(scales, d)
-            t0 = tr.begin()
+            t0 = tr.begin("dispatch", "stage1_chunk")
             gb = _chunk_features_q8(bv, bs, lm, pr,
                                     params, grp, gram_q8_fn)
-            tr.end("kernel", "stage1_chunk", t0, rows=e - s)
+            tr.end(t0, rows=e - s)
         else:
             bx = put(xb, d)
-            t0 = tr.begin()
+            t0 = tr.begin("dispatch", "stage1_chunk")
             gb = _chunk_features(bx, lm, pr, params, gram_fn)
-            tr.end("kernel", "stage1_chunk", t0, rows=e - s)
+            tr.end(t0, rows=e - s)
         st.chunks += 1
         st.rows += e - s
         inflight.append((s, e, gb))

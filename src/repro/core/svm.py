@@ -106,7 +106,7 @@ class LPDSVM:
             tr = resolve_tracer(
                 trace if trace is not None
                 else getattr(self.stream_config, "trace", None))
-            t0 = tr.begin()
+            t0 = tr.begin("fit", "stage1")
             if self.stream or self.stream_config is not None:
                 # Host numpy in, so the streamed path never materialises the
                 # full x on device; the monolithic path converts internally.
@@ -117,8 +117,7 @@ class LPDSVM:
                 stream=self.stream, stream_config=self.stream_config)
             wait_for_factor(self.factor.G)
             self.stats.stage1_seconds = tr.end(
-                "fit", "stage1", t0, rows=int(np.asarray(x).shape[0]),
-                budget=self.budget)
+                t0, rows=int(np.asarray(x).shape[0]), budget=self.budget)
             self.stats.effective_rank = self.factor.effective_rank
             self.stats.stage1_streamed = self.factor.streamed
             self.stats.stage1_stats = getattr(self.factor, "stage1_stats",
@@ -181,13 +180,14 @@ class LPDSVM:
         warm = None
         if warm_alpha is not None:
             warm = [np.asarray(a) for a in warm_alpha]
-        tasks, self.pairs_ = build_ovo_tasks(labels, n_classes, self.C, alpha0=warm)
+        with tr.span("stage2", "build_tasks"):
+            tasks, self.pairs_ = build_ovo_tasks(labels, n_classes, self.C,
+                                                 alpha0=warm)
         self.tasks_ = tasks
-        t0 = tr.begin()
+        t0 = tr.begin("fit", "stage2")
         res: SolveResult = self._solve_stage2(tasks, trace=trace)
         wait_for_factor(res.w)
-        self.stats.stage2_seconds = tr.end("fit", "stage2", t0,
-                                           tasks=tasks.n_tasks)
+        self.stats.stage2_seconds = tr.end(t0, tasks=tasks.n_tasks)
         self.stats.n_tasks = tasks.n_tasks
         self.stats.epochs = np.asarray(res.epochs)
         self.stats.violations = np.asarray(res.violation)
@@ -232,15 +232,24 @@ class LPDSVM:
         return res
 
     # --------------------------------------------------------------- prediction
+    def _tracer(self):
+        return resolve_tracer(getattr(self.stream_config, "trace", None))
+
     def decision_function(self, x: np.ndarray) -> np.ndarray:
         if self.W_ is None:
             raise RuntimeError("fit first")
-        feats = self.factor.features(jnp.asarray(x, jnp.float32))
-        return np.asarray(ovo_decision_values(feats, self.W_))
+        tr = self._tracer()
+        with tr.span("predict", "features"):
+            feats = self.factor.features(jnp.asarray(x, jnp.float32))
+        with tr.span("predict", "decide"):
+            d = ovo_decision_values(feats, self.W_)
+        with tr.span("d2h", "decisions"):
+            return np.asarray(d)
 
     def predict(self, x: np.ndarray) -> np.ndarray:
         d = self.decision_function(x)
-        return self._vote(d)
+        with self._tracer().span("predict", "vote"):
+            return self._vote(d)
 
     def _vote(self, d: np.ndarray) -> np.ndarray:
         if len(self.classes_) == 2:

@@ -9,6 +9,13 @@ same measurements: every hot-path `perf_counter` pair becomes a *span*
 feeds the stats field it always fed, plus instant events (cache hits,
 evictions) and gauge samples (queue depth).
 
+Span categories name what the host is doing, so that a gap on the device
+can be put down to it: ``h2d`` (a host-to-device put), ``d2h`` (a blocking
+device-to-host read), ``dispatch`` (the enqueue of a device program — the
+program itself runs asynchronously), ``read`` (host staging of a G block),
+and bookkeeping categories (``engine``, ``compact``, ``epoch``, ``fit``,
+``stage1``, ``stage2``, ``predict``, ...).
+
 Design constraints, in order:
 
 1. **Near-zero overhead when disabled.**  The module-level `NULL` tracer is
@@ -20,21 +27,34 @@ Design constraints, in order:
 2. **Thread safety.**  The stage-2 farm runs one worker thread per device
    behind a shared reader; recording is a single append of an immutable
    tuple under one lock, and export snapshots under the same lock.
-3. **Two export views.**  ``export(path)`` writes Chrome-trace/Perfetto
-   JSON (open in https://ui.perfetto.dev, one row per thread);
-   ``summary()`` aggregates seconds per category, effective H2D GB/s,
-   rows/s, and the *overlap efficiency* — the fraction of reader/put span
-   time hidden under device compute (kernel/drain spans on other threads).
+3. **Two sinks.**  Every span of a `Tracer` opens a
+   `jax.profiler.TraceAnnotation` named ``<category>/<name>``, so spans
+   land in a `jax.profiler` trace on the same clock as the device's
+   programs (an annotation costs well under a microsecond when no
+   profiler is running).  ``keep=True`` (the default) also records in
+   memory for ``export(path)`` (Chrome-trace/Perfetto JSON, one row per
+   thread) and ``summary()`` (seconds per category, effective H2D GB/s,
+   rows/s); ``Tracer(keep=False)`` mirrors and keeps nothing.  Attrs stay
+   in the in-memory record only; instants and counters are not mirrored.
 
 Usage::
 
     tr = Tracer()
     with tr.span("h2d", "put_block", bytes=nbytes): ...
-    # or the stats-feeding pair form:
-    t0 = tr.begin()
+    # or the stats-feeding pair form (the name is given at `begin`, because
+    # a profiler annotation cannot be backdated):
+    t0 = tr.begin("h2d", "put_block")
     ...
-    stats.put_seconds += tr.end("h2d", "put_block", t0, bytes=nbytes)
+    stats.put_seconds += tr.end(t0, bytes=nbytes)
     tr.export("trace.json"); print(tr.summary())
+
+    # one combined host + device trace:
+    with jax.profiler.trace(log_dir):
+        install(Tracer(keep=False))
+        try:
+            svm.fit(x, y)
+        finally:
+            uninstall()
 
 Call sites resolve their tracer via `resolve(explicit)`: an explicitly
 passed tracer wins, else the process-wide one set by `install()`, else
@@ -46,7 +66,7 @@ import json
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional
 
 __all__ = [
     "Tracer", "NullTracer", "NULL", "ProgressPrinter",
@@ -58,9 +78,6 @@ __all__ = [
 # ph: "X" complete span | "i" instant | "C" counter sample
 # t_abs/dur in perf_counter seconds; attrs a (possibly empty) dict.
 _SPAN, _INSTANT, _COUNTER = "X", "i", "C"
-
-_TRANSFER_CATEGORIES = ("read", "h2d")     # host-side staging / put time
-_COMPUTE_CATEGORIES = ("kernel", "drain")  # device compute / result fetch
 
 
 class _NullSpan:
@@ -91,10 +108,10 @@ class NullTracer:
     __slots__ = ()
     enabled = False
 
-    def begin(self) -> float:
+    def begin(self, category: str, name: str) -> float:
         return time.perf_counter()
 
-    def end(self, category: str, name: str, t0: float, **attrs) -> float:
+    def end(self, t0: float, **attrs) -> float:
         return time.perf_counter() - t0
 
     def span(self, category: str, name: str, **attrs):
@@ -116,7 +133,7 @@ NULL = NullTracer()
 class _Span:
     """Context-manager span for sites that do not feed a stats field."""
 
-    __slots__ = ("_tracer", "category", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "category", "name", "attrs", "_t0", "_mark")
 
     def __init__(self, tracer: "Tracer", category: str, name: str,
                  attrs: dict):
@@ -126,6 +143,7 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        self._mark = self._tracer._open(self.category, self.name)
         self._t0 = time.perf_counter()
         return self
 
@@ -136,17 +154,23 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._mark.__exit__(None, None, None)
         self._tracer._record(_SPAN, self.category, self.name, self._t0,
                              t1 - self._t0, self.attrs)
         return False
 
 
 class Tracer:
-    """Thread-safe in-memory span/instant/counter recorder."""
+    """Thread-safe span/instant/counter recorder: spans into the
+    `jax.profiler` trace, and everything in memory unless ``keep`` is
+    off."""
 
     enabled = True
 
-    def __init__(self):
+    def __init__(self, keep: bool = True):
+        import jax.profiler
+        self.keep = keep
+        self._annotate = jax.profiler.TraceAnnotation
         self._lock = threading.Lock()
         self._events: List[tuple] = []
         self._thread_names: Dict[int, str] = {}
@@ -155,14 +179,18 @@ class Tracer:
         self.t0 = time.perf_counter()
 
     # ---- recording ------------------------------------------------------
-    def begin(self) -> float:
-        """Start a stats-feeding span; pair with `end`."""
-        return time.perf_counter()
+    def begin(self, category: str, name: str) -> tuple:
+        """Start a stats-feeding span and open its profiler annotation;
+        pair with `end`, which takes the handle this returns."""
+        mark = self._open(category, name)
+        return (category, name, time.perf_counter(), mark)
 
-    def end(self, category: str, name: str, t0: float, **attrs) -> float:
+    def end(self, handle: tuple, **attrs) -> float:
         """Close a `begin` span, record it, and return its duration so call
         sites can feed the existing stats field in the same expression."""
         t1 = time.perf_counter()
+        category, name, t0, mark = handle
+        mark.__exit__(None, None, None)
         self._record(_SPAN, category, name, t0, t1 - t0, attrs)
         return t1 - t0
 
@@ -186,14 +214,21 @@ class Tracer:
         the lock — keep them cheap and thread-safe."""
         self._listeners.append(fn)
 
+    def _open(self, category: str, name: str):
+        """The profiler annotation of a span, entered."""
+        mark = self._annotate(f"{category}/{name}")
+        mark.__enter__()
+        return mark
+
     def _record(self, ph: str, category: str, name: str, t_abs: float,
                 dur: float, attrs: dict) -> None:
         tid = threading.get_ident()
         ev = (ph, category, name, t_abs, dur, tid, attrs)
-        with self._lock:
-            if tid not in self._thread_names:
-                self._thread_names[tid] = threading.current_thread().name
-            self._events.append(ev)
+        if self.keep:
+            with self._lock:
+                if tid not in self._thread_names:
+                    self._thread_names[tid] = threading.current_thread().name
+                self._events.append(ev)
         for fn in self._listeners:
             fn(ev)
 
@@ -250,7 +285,7 @@ class Tracer:
     # ---- aggregation ----------------------------------------------------
     def summary(self) -> str:
         """Aggregated text view: seconds/records per category, effective
-        H2D GB/s, rows/s, and timeline overlap efficiency."""
+        H2D GB/s and rows/s."""
         events = self.events()
         spans = [e for e in events if e[0] == _SPAN]
         if not events:
@@ -281,15 +316,10 @@ class Tracer:
             lines.append(f"  effective H2D: "
                          f"{h2d_bytes / max(h2d_secs, 1e-12) / 1e9:.2f} GB/s "
                          f"({h2d_bytes / 1e9:.3f} GB in {h2d_secs:.3f}s)")
-        rows = sum(e[6].get("rows", 0) for e in by_cat.get("kernel", []))
+        rows = sum(e[6].get("rows", 0) for e in by_cat.get("dispatch", []))
         if rows:
             lines.append(f"  rows/s: {rows / wall:,.0f} "
                          f"({rows:,} row visits in {wall:.3f}s wall)")
-        ov = self.overlap_efficiency()
-        if ov is not None:
-            lines.append(f"  overlap efficiency: {ov:.2f} "
-                         f"(fraction of read/h2d time hidden under "
-                         f"compute on other threads)")
         for cat, label in (("cache", "cache events"),
                            ("fault", "fault events"),
                            ("recovery", "recovery events")):
@@ -301,56 +331,6 @@ class Tracer:
                 lines.append(f"  {label}: " + ", ".join(
                     f"{k}={v}" for k, v in sorted(inst.items())))
         return "\n".join(lines)
-
-    def overlap_efficiency(self) -> Optional[float]:
-        """Fraction of transfer (read/h2d) span time that overlaps compute
-        (kernel/drain) spans *on other threads* — the timeline analogue of
-        the stats-level `overlap_efficiency` properties.  None when there
-        are no transfer spans; 0.0 in single-thread (inline) runs, where
-        nothing can be hidden."""
-        spans = [e for e in self.events() if e[0] == _SPAN]
-        xfer = [e for e in spans if e[1] in _TRANSFER_CATEGORIES]
-        comp = [(e[3], e[3] + e[4], e[5]) for e in spans
-                if e[1] in _COMPUTE_CATEGORIES]
-        if not xfer:
-            return None
-        total = sum(e[4] for e in xfer)
-        if total <= 0.0:
-            return 0.0
-        hidden = 0.0
-        merged_cache: Dict[int, List[Tuple[float, float]]] = {}
-        for ph, cat, name, t_abs, dur, tid, attrs in xfer:
-            if tid not in merged_cache:
-                merged_cache[tid] = _merge_intervals(
-                    [(a, b) for a, b, ctid in comp if ctid != tid])
-            hidden += _overlap_with(t_abs, t_abs + dur, merged_cache[tid])
-        return min(1.0, hidden / total)
-
-
-def _merge_intervals(iv: Sequence[Tuple[float, float]]
-                     ) -> List[Tuple[float, float]]:
-    """Union of half-open intervals, sorted and non-overlapping."""
-    out: List[Tuple[float, float]] = []
-    for a, b in sorted(iv):
-        if out and a <= out[-1][1]:
-            if b > out[-1][1]:
-                out[-1] = (out[-1][0], b)
-        else:
-            out.append((a, b))
-    return out
-
-
-def _overlap_with(a: float, b: float,
-                  merged: Sequence[Tuple[float, float]]) -> float:
-    """Length of [a, b) covered by a merged interval list."""
-    cov = 0.0
-    for lo, hi in merged:
-        if hi <= a:
-            continue
-        if lo >= b:
-            break
-        cov += min(b, hi) - max(a, lo)
-    return cov
 
 
 def _json_default(o):

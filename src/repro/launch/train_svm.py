@@ -353,8 +353,8 @@ def main():
                          "Perfetto / chrome://tracing")
     ap.add_argument("--trace-summary", action="store_true",
                     help="print the aggregated trace summary (seconds per "
-                         "category, effective H2D GB/s, rows/s, overlap "
-                         "efficiency) after the run; implies tracing")
+                         "category, effective H2D GB/s, rows/s, cache "
+                         "events) after the run; implies tracing")
     ap.add_argument("--verbose", action="store_true",
                     help="print one progress line per stage-2 epoch (active "
                          "rows, bytes, cache hit rate, rows/s, max KKT "

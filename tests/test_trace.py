@@ -7,12 +7,16 @@ AND every device worker thread; (c) the disabled-mode contract: a live but
 UNINSTALLED tracer records zero events, and a traced solve is bit-identical
 to an untraced one (tracing observes, never steers); (d) the derived-rate
 properties (`h2d_gbps`, `overlap_efficiency`) shared by the stats
-dataclasses and the benchmarks; (e) the timeline overlap-efficiency
-computation on synthetic spans with known geometry.
+dataclasses and the benchmarks; (e) the profiler mirror: every span opens
+one `<category>/<name>` annotation, nested as the spans are, and a real
+`jax.profiler` trace of a streamed fit holds them; (f) the exact
+`h2d_puts` / `d2h_syncs` counts of a streamed solve.
 """
+import collections
 import io
 import json
 import threading
+import time
 
 import numpy as np
 import jax.numpy as jnp
@@ -21,10 +25,9 @@ import pytest
 from repro.core import (KernelParams, SolverConfig, StreamConfig,
                         compute_factor, solve_batch_streamed)
 from repro.core.ovo import build_ovo_tasks
-from repro.core.solver_stream import Stage2StreamStats
+from repro.core.solver_stream import Stage2StreamStats, merge_stream_stats
 from repro.core.streaming import Stage1StreamStats
 from repro.core.svm import LPDSVM
-from repro.core import trace as T
 from repro.core.trace import (NULL, NullTracer, ProgressPrinter, Tracer,
                               install, resolve, uninstall)
 from repro.data import make_multiclass
@@ -45,19 +48,19 @@ def _problem(n=240, classes=3, budget=48, C=2.0, seed=3):
 
 def test_record_span_instant_counter():
     tr = Tracer()
-    t0 = tr.begin()
-    dt = tr.end("h2d", "put", t0, bytes=1024)
+    t0 = tr.begin("h2d", "put")
+    dt = tr.end(t0, bytes=1024)
     assert dt >= 0.0
-    with tr.span("kernel", "sweep", rows=8) as sp:
+    with tr.span("dispatch", "smo", rows=8) as sp:
         sp.set(extra=1)
     tr.instant("cache", "hit", bytes=64)
     tr.counter("queue_depth/dev0", 3)
     cats = tr.categories()
-    assert cats == {"h2d": 1, "kernel": 1, "cache": 1, "counter": 1}
+    assert cats == {"h2d": 1, "dispatch": 1, "cache": 1, "counter": 1}
     evs = tr.events()
     ph = sorted(e[0] for e in evs)
     assert ph == ["C", "X", "X", "i"]
-    kern = [e for e in evs if e[1] == "kernel"][0]
+    kern = [e for e in evs if e[1] == "dispatch"][0]
     assert kern[6] == {"rows": 8, "extra": 1}
 
 
@@ -65,11 +68,13 @@ def test_end_duration_feeds_stats_semantics():
     """`end` returns the same elapsed-seconds quantity a perf_counter pair
     would, so `put_seconds += tr.end(...)` preserves stats meanings."""
     tr = Tracer()
-    t0 = tr.begin()
-    dt = tr.end("h2d", "put", t0)
+    before = time.perf_counter()
+    t0 = tr.begin("h2d", "put")
+    dt = tr.end(t0)
     ev = tr.events()[0]
+    assert ev[1:3] == ("h2d", "put")
     assert ev[4] == pytest.approx(dt)
-    assert ev[3] == pytest.approx(t0)
+    assert before <= ev[3] <= time.perf_counter() - dt
 
 
 def test_listener_sees_raw_tuples():
@@ -85,8 +90,8 @@ def test_listener_sees_raw_tuples():
 
 def test_export_chrome_trace_schema(tmp_path):
     tr = Tracer()
-    t0 = tr.begin()
-    tr.end("h2d", "put", t0, bytes=int(np.int64(4096)))
+    t0 = tr.begin("h2d", "put")
+    tr.end(t0, bytes=int(np.int64(4096)))
     tr.instant("cache", "hit", bytes=np.int32(64))
     tr.counter("depth", np.float32(2.0))
     path = tmp_path / "t.json"
@@ -132,7 +137,7 @@ def test_export_thread_rows(tmp_path):
 
 def _synthetic_span(tr, cat, name, t_abs, dur, tid_thread=None, **attrs):
     """Record a span with controlled geometry (optionally from a named
-    thread so overlap sees distinct tids)."""
+    thread, so that it carries a distinct tid)."""
     if tid_thread is None:
         tr._record("X", cat, name, t_abs, dur, attrs)
         return
@@ -143,43 +148,15 @@ def _synthetic_span(tr, cat, name, t_abs, dur, tid_thread=None, **attrs):
     th.join()
 
 
-def test_overlap_efficiency_geometry():
-    """h2d [0,2) vs other-thread kernel [1,3): exactly half hidden."""
-    tr = Tracer()
-    _synthetic_span(tr, "h2d", "put", 0.0, 2.0)
-    _synthetic_span(tr, "kernel", "sweep", 1.0, 2.0, tid_thread="w0")
-    assert tr.overlap_efficiency() == pytest.approx(0.5)
-
-
-def test_overlap_efficiency_same_thread_not_hidden():
-    """Compute on the SAME thread cannot hide that thread's transfers."""
-    tr = Tracer()
-    _synthetic_span(tr, "h2d", "put", 0.0, 2.0)
-    _synthetic_span(tr, "kernel", "sweep", 0.0, 2.0)
-    assert tr.overlap_efficiency() == pytest.approx(0.0)
-
-
-def test_overlap_efficiency_none_without_transfers():
-    tr = Tracer()
-    _synthetic_span(tr, "kernel", "sweep", 0.0, 1.0)
-    assert tr.overlap_efficiency() is None
-
-
-def test_merge_and_overlap_helpers():
-    merged = T._merge_intervals([(3.0, 4.0), (0.0, 1.0), (0.5, 2.0)])
-    assert merged == [(0.0, 2.0), (3.0, 4.0)]
-    assert T._overlap_with(0.5, 3.5, merged) == pytest.approx(2.0)
-
-
 def test_summary_reports_figures():
     tr = Tracer()
     _synthetic_span(tr, "h2d", "put", 0.0, 1.0, bytes=10**9)
-    _synthetic_span(tr, "kernel", "sweep", 0.5, 1.5, tid_thread="w0",
+    _synthetic_span(tr, "dispatch", "smo", 0.5, 1.5, tid_thread="w0",
                     rows=1000)
     s = tr.summary()
     assert "effective H2D" in s
     assert "rows/s" in s
-    assert "overlap efficiency" in s
+    assert "dispatch" in s and "2 threads" in s
 
 
 def test_progress_printer_line():
@@ -187,9 +164,9 @@ def test_progress_printer_line():
     pp = ProgressPrinter(stream=buf)
     tr = Tracer()
     tr.add_listener(pp)
-    t0 = tr.begin()
-    tr.end("epoch", "epoch_3", t0, epoch=3, kind="cheap", bytes=10**6,
-           hit_bytes=3, miss_bytes=1, rows=100, active=42, viol=0.25)
+    t0 = tr.begin("epoch", "cheap")
+    tr.end(t0, epoch=3, kind="cheap", bytes=10**6, hit_bytes=3,
+           miss_bytes=1, rows=100, active=42, viol=0.25)
     line = buf.getvalue()
     assert "epoch    3" in line and "[cheap]" in line
     assert "active=      42" in line and "hit=75.0%" in line
@@ -201,10 +178,11 @@ def test_progress_printer_line():
 # ------------------------------------------------------ disabled-mode no-op
 
 def test_null_tracer_records_nothing_and_still_times():
-    t0 = NULL.begin()
-    dt = NULL.end("h2d", "put", t0, bytes=1)
+    t0 = NULL.begin("h2d", "put")
+    assert isinstance(t0, float)
+    dt = NULL.end(t0, bytes=1)
     assert isinstance(dt, float) and dt >= 0.0
-    with NULL.span("kernel", "sweep") as sp:
+    with NULL.span("dispatch", "smo") as sp:
         sp.set(rows=1)
     NULL.instant("cache", "hit")
     NULL.counter("q", 1)
@@ -236,7 +214,8 @@ def test_uninstalled_spy_records_zero_events():
 
 
 def test_traced_solve_bit_identical_to_untraced():
-    """Tracing observes the pipeline; it must not steer it."""
+    """Tracing observes the pipeline; it must not steer it — with both
+    sinks on, the in-memory record and the profiler annotations."""
     G, tasks = _problem()
     cfg0 = StreamConfig(tile_rows=64)
     res0, st0 = solve_batch_streamed(jnp.asarray(G), tasks,
@@ -253,6 +232,7 @@ def test_traced_solve_bit_identical_to_untraced():
     assert np.array_equal(np.asarray(res0.epochs), np.asarray(res1.epochs))
     assert st0.bytes_h2d == st1.bytes_h2d
     assert st0.epoch_bytes == st1.epoch_bytes
+    assert (st0.h2d_puts, st0.d2h_syncs) == (st1.h2d_puts, st1.d2h_syncs)
 
 
 # ----------------------------------------------------- derived-rate dedup
@@ -284,8 +264,10 @@ def test_streamed_solve_emits_pipeline_spans():
     _, st = solve_batch_streamed(jnp.asarray(G), tasks, SolverConfig(tol=1e-2),
                                  stream_config=cfg, return_stats=True)
     cats = tr.categories()
-    for want in ("h2d", "kernel", "epoch"):
+    for want in ("h2d", "dispatch", "d2h", "engine", "epoch"):
         assert cats.get(want, 0) > 0, cats
+    assert "kernel" not in cats and "drain" not in cats
+    assert not any(e[0] == "C" for e in tr.events())
     # span durations ARE the stats: the h2d spans sum to put_seconds
     h2d = sum(e[4] for e in tr.events()
               if e[0] == "X" and e[1] == "h2d")
@@ -322,6 +304,132 @@ def test_fit_trace_without_stream_config_covers_polish():
     assert levels == [f"level_{i}" for i in range(len(levels))] and levels
 
 
+# ------------------------------------------------------- profiler mirror
+
+class _Marks:
+    """Stand-in for `jax.profiler.TraceAnnotation` that logs each enter and
+    exit by name."""
+
+    log = []
+
+    def __init__(self, name, **kwargs):
+        assert not kwargs              # attrs must never reach the name
+        self.name = name
+
+    def __enter__(self):
+        _Marks.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _Marks.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def marks(monkeypatch):
+    import jax.profiler
+    _Marks.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Marks)
+    return _Marks.log
+
+
+def test_mirror_opens_one_annotation_per_span_nested(marks):
+    tr = Tracer()
+    t0 = tr.begin("engine", "feed_block")
+    with tr.span("dispatch", "smo", rows=3):
+        pass
+    t1 = tr.begin("h2d", "put_vec")
+    tr.end(t1, bytes=64)
+    tr.end(t0)
+    tr.instant("cache", "hit")
+    tr.counter("depth", 1)
+    assert marks == [("enter", "engine/feed_block"),
+                     ("enter", "dispatch/smo"), ("exit", "dispatch/smo"),
+                     ("enter", "h2d/put_vec"), ("exit", "h2d/put_vec"),
+                     ("exit", "engine/feed_block")]
+    # The in-memory record is unchanged by the mirror: attrs live there.
+    assert tr.categories() == {"engine": 1, "dispatch": 1, "h2d": 1,
+                               "cache": 1, "counter": 1}
+    put = [e for e in tr.events() if e[2] == "put_vec"][0]
+    assert put[6] == {"bytes": 64}
+
+
+def test_mirror_only_tracer_keeps_nothing(marks):
+    tr = Tracer(keep=False)
+    assert tr.enabled
+    seen = []
+    tr.add_listener(seen.append)
+    t0 = tr.begin("d2h", "block_drain")
+    assert tr.end(t0, bytes=8) >= 0.0
+    tr.instant("cache", "miss")
+    assert tr.n_events == 0 and tr.events() == []
+    assert marks == [("enter", "d2h/block_drain"), ("exit", "d2h/block_drain")]
+    assert [e[1] for e in seen] == ["d2h", "cache"]
+
+
+def test_null_tracer_opens_no_annotation(marks):
+    NULL.end(NULL.begin("h2d", "put_vec"))
+    with NULL.span("dispatch", "smo"):
+        pass
+    assert marks == []
+
+
+def test_streamed_solve_mirrors_every_span(marks):
+    """Each in-memory span of a streamed solve has its annotation."""
+    G, tasks = _problem()
+    tr = Tracer()
+    solve_batch_streamed(jnp.asarray(G), tasks, SolverConfig(tol=1e-2),
+                         stream_config=StreamConfig(tile_rows=64, trace=tr))
+    spans = collections.Counter(f"{e[1]}/{e[2]}" for e in tr.events()
+                                if e[0] == "X")
+    opened = collections.Counter(n for k, n in marks if k == "enter")
+    assert opened == spans
+    assert collections.Counter(n for k, n in marks if k == "exit") == spans
+    for name in ("h2d/put_vec", "h2d/put_block", "dispatch/smo",
+                 "dispatch/window", "dispatch/row_sq", "d2h/block_drain",
+                 "d2h/violation", "d2h/result", "engine/feed_block",
+                 "engine/end_pass", "compact/recompact", "epoch/full"):
+        assert spans[name] > 0, name
+
+
+# ------------------------------------------------ round-trip counters
+
+def _binary_problem(n=300, budget=32, seed=5):
+    x, y = make_multiclass(n, p=4, n_classes=2, seed=seed)
+    _, labels = np.unique(y, return_inverse=True)
+    fac = compute_factor(jnp.asarray(x, jnp.float32),
+                         KernelParams("rbf", gamma=0.5), budget)
+    tasks, _ = build_ovo_tasks(labels, 2, 1.0)
+    return np.asarray(fac.G), tasks
+
+
+@pytest.mark.parametrize("wire,puts_per_block", [("f32", 1), ("int8", 2)])
+def test_put_and_sync_counts_match_a_hand_count(wire, puts_per_block):
+    """One task over every row: each SMO window puts 5 vectors and is
+    drained by 2 reads; each G block put is 1 put (int8: codes and scales);
+    each full pass reads one violation per block; the result reads W."""
+    G, tasks = _binary_problem()
+    tile = 64
+    _, st = solve_batch_streamed(
+        G, tasks, SolverConfig(tol=1e-3),
+        stream_config=StreamConfig(tile_rows=tile, block_dtype=wire),
+        return_stats=True)
+    n_blocks = -(-G.shape[0] // tile)
+    assert st.full_passes >= 2 and st.kernel_calls > n_blocks
+    assert st.h2d_puts == (5 * st.kernel_calls
+                           + puts_per_block * st.blocks_streamed)
+    assert st.d2h_syncs == (2 * st.kernel_calls
+                            + st.full_passes * n_blocks + 1)
+
+
+def test_merge_sums_round_trip_counters():
+    a = Stage2StreamStats(h2d_puts=7, d2h_syncs=3)
+    b = Stage2StreamStats(h2d_puts=5, d2h_syncs=2)
+    m = merge_stream_stats(Stage2StreamStats(), [a, b], seconds=1.0,
+                           n_devices=2)
+    assert (m.h2d_puts, m.d2h_syncs) == (12, 5)
+
+
 # ------------------------------------------------- 2-device farm (subprocess)
 
 FARM_CODE = r"""
@@ -354,7 +462,7 @@ cats = sorted({e["cat"] for e in evs if e["ph"] == "X"})
 print("NAMES:" + json.dumps(names))
 print("TIDS:%d" % len(span_tids))
 print("CATS:" + json.dumps(cats))
-print("SUMMARY_OK:%d" % ("overlap" in tr.summary()))
+print("SUMMARY_OK:%d" % ("rows/s" in tr.summary()))
 """
 
 
@@ -369,6 +477,6 @@ def test_farm_trace_covers_all_threads():
     assert "worker/dev0" in names and "worker/dev1" in names
     assert int(lines["TIDS"]) >= 3
     cats = json.loads(lines["CATS"])
-    for want in ("read", "h2d", "kernel", "queue", "epoch"):
+    for want in ("read", "h2d", "dispatch", "d2h", "queue", "epoch"):
         assert want in cats, cats
     assert lines["SUMMARY_OK"] == "1"
