@@ -1,10 +1,14 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+import logging
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.kernel_fn import KernelParams
 from repro.kernels import ops, ref
+from repro.kernels.smo import smo_epoch_pallas
 
 
 @pytest.mark.parametrize("n,m,p", [
@@ -154,3 +158,72 @@ def test_smo_epoch_tile_sweep(rng, tn):
         unch[:, None], w[None, :], full_pass=True)
     np.testing.assert_allclose(np.asarray(a1), np.asarray(a2[:, 0]), atol=3e-6)
     np.testing.assert_allclose(np.asarray(w1), np.asarray(w2[0]), atol=3e-5)
+
+
+def _smo_epoch_eager(G, y, c, q, alpha, unch, w, *, full_pass, tn=256):
+    """The wrapper as it was before it became one program: eager casts,
+    pads and reshapes around the jitted Pallas call, then eager slices."""
+    n = G.shape[0]
+    tn = min(tn, max(8, 1 << (n - 1).bit_length())) if n < tn else tn
+    col = lambda v, dt: ops._pad_axis(jnp.asarray(v, dt).reshape(-1, 1), 0, tn)
+    a, u, wv, viol = smo_epoch_pallas(
+        ops._pad_axis(jnp.asarray(G, jnp.float32), 0, tn),
+        col(y, jnp.float32), col(c, jnp.float32), col(q, jnp.float32),
+        col(alpha, jnp.float32), col(unch, jnp.int32),
+        jnp.asarray(w, jnp.float32).reshape(1, -1),
+        full_pass=full_pass, tn=tn, interpret=True)
+    return a[:n, 0], u[:n, 0], wv[0], viol[0, 0]
+
+
+@pytest.mark.parametrize("full_pass", [True, False])
+@pytest.mark.parametrize("n", [37, 200, 256, 300])
+def test_smo_epoch_one_program_bit_identical(rng, n, full_pass):
+    """One compiled program gives the eager composition's bits; 37, 200 and
+    300 are not multiples of their tile, so the row padding is traced."""
+    args = _smo_inputs(rng, n, 64)
+    got = ops.smo_epoch(*args, full_pass=full_pass, interpret=True)
+    want = _smo_epoch_eager(*args, full_pass=full_pass)
+    for g, e in zip(got, want):
+        assert g.shape == e.shape and g.dtype == e.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+
+
+class _Compiles(logging.Handler):
+    """Collects the names of the programs XLA compiles under
+    `jax.log_compiles()`."""
+
+    def __init__(self):
+        super().__init__()
+        self.names = []
+
+    def emit(self, record):
+        msg = record.getMessage()
+        if msg.startswith("Finished XLA compilation of "):
+            self.names.append(msg.split(" of ", 1)[1].split(" in ")[0])
+
+
+def test_smo_epoch_is_one_program_per_call(rng):
+    # bench/metrics/smo_roofline.py sums the device time of the XLA modules
+    # whose name holds its PROGRAM, "smo_epoch_pallas": the wrapper's one
+    # module has to carry it, and no other module may.
+    n, B = 45, 40          # a shape no other test compiles
+    args = _smo_inputs(rng, n, B)
+    text = ops.smo_epoch_pallas_flat.lower(
+        *args, full_pass=True, shrink_k=5, tn=256, interpret=True).as_text()
+    assert text.count("module @") == 1
+    assert "module @jit_smo_epoch_pallas_flat " in text
+
+    log, handler = logging.getLogger("jax"), _Compiles()
+    log.addHandler(handler)
+    try:
+        with jax.log_compiles():
+            out = ops.smo_epoch(*args, full_pass=True, interpret=True)
+            jax.block_until_ready(out)
+    finally:
+        log.removeHandler(handler)
+    assert handler.names == ["jit(smo_epoch_pallas_flat)"]
+
+    size = ops.smo_epoch_pallas_flat._cache_size()
+    out = ops.smo_epoch(*args, full_pass=True, interpret=True)
+    jax.block_until_ready(out)
+    assert ops.smo_epoch_pallas_flat._cache_size() == size
